@@ -14,25 +14,24 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.training.grad_compress import compressed_psum
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.analysis.hlo import analyze
 
-mesh = compat.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 x = jax.ShapeDtypeStruct((1024, 1024), jnp.float32)
 
 def plain(v):
-    return shard_map(lambda t: jax.lax.psum(t, "data"), mesh=mesh,
-                     in_specs=P(None, None), out_specs=P(None, None),
-                     check_rep=False)(v)
+    return jax.shard_map(lambda t: jax.lax.psum(t, "data"), mesh=mesh,
+                         in_specs=P(None, None), out_specs=P(None, None),
+                         check_vma=False)(v)
 
 def comp(v):
     return compressed_psum(v, mesh, "data")
 
 out = {}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for name, fn in (("psum_fp32", plain), ("psum_int8_ef", comp)):
         c = jax.jit(fn).lower(x).compile()
         a = analyze(c.as_text(), 8)
@@ -44,12 +43,15 @@ print("JSON:" + json.dumps(out))
 
 def main(quick: bool = False):
     src = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the child counts HLO bytes on virtual CPU devices: it must never ask
+    # for an accelerator, which the parent process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", CODE, src],
-                         capture_output=True, text=True, timeout=560)
+                         capture_output=True, text=True, timeout=560, env=env)
     line = [l for l in out.stdout.splitlines() if l.startswith("JSON:")]
-    if not line:
-        return [{"name": "grad_compress", "us_per_call": 0,
-                 "derived": "subprocess failed: " + out.stderr[-200:]}]
+    if out.returncode != 0 or not line:
+        raise RuntimeError(f"grad_compress child failed (exit "
+                           f"{out.returncode}): {out.stderr[-2000:]}")
     d = json.loads(line[0][5:])
     fp32 = d["psum_fp32"]["coll_bytes_per_dev"]
     int8 = d["psum_int8_ef"]["coll_bytes_per_dev"]

@@ -1,6 +1,7 @@
-"""Kernel micro-bench: numerics vs oracle + CPU timing of the jnp reference
-path (interpret-mode Pallas timing is meaningless; on TPU flip
-REPRO_PALLAS_COMPILE=1 and the same harness times the real kernels)."""
+"""Kernel micro-bench: numerics of each Pallas kernel vs its oracle, plus
+the wall time of the jnp reference path.  ``repro.kernels.ops`` picks the
+mode from the backend: on the CPU the kernels run in interpret mode (so
+only their numerics mean anything here), on a TPU they are compiled."""
 from __future__ import annotations
 
 import time

@@ -27,7 +27,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.registry import key_arch, key_for
 from repro.serving.engine import Engine, cache_batch_axes_for
-from repro.sharding import rules_for
+from repro.sharding import rules_for, shardings_for
 from repro.training import steps as ST
 
 KINDS = ("prefill", "decode")
@@ -156,12 +156,51 @@ class Workload:
                           batch=static["batch"], seq=self.seq,
                           eos_id=self.eos_id)
 
+    # ----------------------------------------------------------- placement --
+    def param_shardings(self):
+        """Where each param leaf lives on this workload's mesh (serve
+        rules: weights split over ``model`` only, so a data-parallel mesh
+        holds one full copy per device)."""
+        return shardings_for(M.param_axes(self.cfg),
+                             M.abstract_params(self.cfg), self.mesh,
+                             self.rules)
+
+    def cache_shardings(self):
+        """Where the serving slot caches live (batch over the data axes)."""
+        caches = jax.eval_shape(
+            lambda: M.init_cache(self.cfg, self.batch, self.cache_len))
+        return shardings_for(M.cache_axes(self.cfg), caches, self.mesh,
+                             self.rules)
+
+    def in_shardings(self, kind: str) -> tuple:
+        """Per-argument placement of ``kind``'s step.  Recordings are
+        compiled for exactly this placement, and ``params`` / the engine's
+        caches are made there, so a replayed executable never meets an
+        argument it would have to reshard on every call."""
+        _fn, specs, _donate = self.step(kind)
+        p_axes = M.param_axes(self.cfg)
+        axes = (p_axes, {"tokens": ("batch", "seq")}) if kind == "prefill" \
+            else (p_axes, ("batch",), ("batch",), M.cache_axes(self.cfg))
+        return shardings_for(axes, specs, self.mesh, self.rules)
+
+    def _compile_kwargs(self, kind: str) -> dict:
+        """Everything but the name that ``record`` / ``compile_artifact``
+        need for ``kind``: step, abstract args, placement, identity."""
+        fn, specs, donate = self.step(kind)
+        return dict(fn=fn, args_abstract=specs, mesh=self.mesh,
+                    in_shardings=self.in_shardings(kind),
+                    donate_argnums=donate,
+                    config_fingerprint=self.config_fp,
+                    static_meta=self.static_meta(kind))
+
     def params(self, seed: int = 0):
-        """Initialized model params, memoized per seed (so solo engines
-        and scheduler streams built from one workload share arrays)."""
+        """Initialized model params placed on this workload's mesh,
+        memoized per seed (so solo engines and scheduler streams built
+        from one workload share arrays)."""
         if seed not in self._params:
-            self._params[seed] = M.init_params(self.cfg,
-                                               jax.random.PRNGKey(seed))
+            self._params[seed] = jax.device_put(
+                M.init_params(self.cfg, jax.random.PRNGKey(seed)),
+                self.param_shardings())
         return self._params[seed]
 
     # -------------------------------------------------------------- record --
@@ -170,11 +209,7 @@ class Workload:
         protocol.  Use with ``record(artifact=...)`` to amortize ONE
         compile across several session variants (serialized executables
         are not byte-deterministic across recompiles)."""
-        fn, specs, donate = self.step(kind)
-        return compile_artifact(self.key(kind), fn, specs, mesh=self.mesh,
-                                donate_argnums=donate,
-                                config_fingerprint=self.config_fp,
-                                static_meta=self.static_meta(kind))
+        return compile_artifact(self.key(kind), **self._compile_kwargs(kind))
 
     def record(self, kind: str = "prefill", *, passes=None,
                artifact: Optional[Recording] = None,
@@ -194,11 +229,8 @@ class Workload:
                                              artifact.payload,
                                              artifact.trees))
         else:
-            fn, specs, donate = self.step(kind)
-            rec = record(self.key(kind), fn, specs, mesh=self.mesh,
-                         donate_argnums=donate,
-                         config_fingerprint=self.config_fp,
-                         static_meta=self.static_meta(kind), session=session)
+            rec = record(self.key(kind), session=session,
+                         **self._compile_kwargs(kind))
         self.sessions.append((kind, session.report()))
         return rec
 
@@ -303,14 +335,9 @@ class Workload:
         """Record-on-miss closure: the service's single-flight lease
         supplies the session, so the miss records through the service's
         configured link profile with THIS workload's exact shapes."""
-        static = self.static_meta(kind)
-
         def record_fn(session=None):
-            fn, specs, donate = self.step(kind)
-            return record(reg_key, fn, specs, mesh=self.mesh,
-                          donate_argnums=donate,
-                          config_fingerprint=self.config_fp,
-                          static_meta=static, session=session)
+            return record(reg_key, session=session,
+                          **self._compile_kwargs(kind))
         return record_fn
 
     def fetch(self, kind: str = "prefill", *, record_on_miss: bool = False,
@@ -380,12 +407,24 @@ class Workload:
 
     def _live_channel(self) -> LiveChannel:
         """Live-jit transport, memoized: every engine/scheduler built
-        from this workload shares the same compiled step functions."""
+        from this workload shares the same compiled step functions.  Each
+        step is traced and called under this workload's mesh, as the
+        recorder traces it: outside a mesh the steps' sharding constraints
+        are dropped, and the compiler fuses differently — on a TPU that
+        alone changes the bf16 rounding, and with it greedy tokens."""
         if self._live is None:
-            cfg, rules = self.cfg, self.rules
-            prefill_fn = jax.jit(
+            cfg, rules, mesh = self.cfg, self.rules, self.mesh
+
+            def on_mesh(fn, **jit_kw):
+                jitted = jax.jit(fn, **jit_kw)
+
+                def call(*args):
+                    with jax.set_mesh(mesh):
+                        return jitted(*args)
+                return call
+            prefill_fn = on_mesh(
                 ST.make_prefill_step(cfg, rules, self.cache_len))
-            decode_fn = jax.jit(
+            decode_fn = on_mesh(
                 ST.make_fused_decode_step(cfg, rules, k=self.block_k,
                                           eos_id=self.eos_id),
                 donate_argnums=(3,))
@@ -394,7 +433,7 @@ class Workload:
             # position-indexed), and SWA ring layout needs true lengths
             batched_prefill = None
             if cfg.family in ("dense", "moe") and not cfg.sliding_window:
-                batched_prefill = jax.jit(
+                batched_prefill = on_mesh(
                     ST.make_batched_prefill_step(cfg, rules, self.cache_len))
             self._live = LiveChannel(prefill_fn, decode_fn, batched_prefill)
         return self._live
@@ -436,10 +475,14 @@ class Workload:
 
     def stream_kwargs(self, *, speculate: bool = True,
                       pipeline_depth: int = 4) -> dict:
-        return stream_kwargs(self.cfg, n_slots=self.batch,
-                             cache_len=self.cache_len, block_k=self.block_k,
-                             eos_id=self.eos_id, speculate=speculate,
-                             pipeline_depth=pipeline_depth)
+        kw = stream_kwargs(self.cfg, n_slots=self.batch,
+                           cache_len=self.cache_len, block_k=self.block_k,
+                           eos_id=self.eos_id, speculate=speculate,
+                           pipeline_depth=pipeline_depth)
+        # fresh slot caches start where the decode step expects them
+        init, shardings = kw["init_caches_fn"], self.cache_shardings()
+        kw["init_caches_fn"] = lambda: jax.device_put(init(), shardings)
+        return kw
 
     def engine(self, params=None, *, seed: int = 0, channel=None,
                recordings_dir: str = "", record_on_miss: bool = False,

@@ -25,7 +25,6 @@ from typing import Any, Optional, Sequence
 import jax
 from jax.experimental import serialize_executable as se
 
-from repro.compat import cost_analysis, set_mesh
 from repro.core.attest import fingerprint
 from repro.core.recording import Recording
 
@@ -52,7 +51,7 @@ def compile_artifact(name: str, fn, args_abstract: Sequence[Any], *,
         kw["out_shardings"] = out_shardings
     jitted = jax.jit(fn, donate_argnums=donate_argnums, **kw)
     if mesh is not None:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(*args_abstract)
             compiled = lowered.compile()
     else:
@@ -69,11 +68,16 @@ def compile_artifact(name: str, fn, args_abstract: Sequence[Any], *,
         "jax_version": jax.__version__,
         "topology": topology_fingerprint(),
         "mesh": mesh_descriptor(mesh) if mesh is not None else None,
+        # the executable's device assignment, in order: replay loads it
+        # onto exactly these devices, not onto every visible one
+        "devices": [d.id for d in
+                    compiled.runtime_executable().local_devices()],
         "config_fingerprint": config_fingerprint,
         "donate": list(donate_argnums),
         "inputs": [{"shape": list(getattr(a, "shape", ())),
                     "dtype": str(getattr(a, "dtype", ""))} for a in flat],
-        "cost": {k: float(v) for k, v in cost_analysis(compiled).items()
+        "cost": {k: float(v)
+                 for k, v in (compiled.cost_analysis() or {}).items()
                  if isinstance(v, (int, float))},
         "memory": {
             "arg_bytes": compiled.memory_analysis().argument_size_in_bytes,

@@ -56,6 +56,20 @@ def _topology_fingerprint() -> str:
     return fingerprint(sorted(str(d.device_kind) for d in devs), len(devs))
 
 
+def _execution_devices(manifest: dict):
+    """The recorded device assignment (None: every visible device, for
+    recordings that predate the field)."""
+    ids = manifest.get("devices")
+    if ids is None:
+        return None
+    by_id = {d.id: d for d in jax.devices()}
+    missing = [i for i in ids if i not in by_id]
+    if missing:
+        raise TopologyMismatchError(
+            f"recording runs on devices {ids}; {missing} not present")
+    return [by_id[i] for i in ids]
+
+
 def _aval_signature(leaves) -> tuple:
     return tuple((tuple(getattr(a, "shape", ())),
                   str(getattr(a, "dtype", ""))) for a in leaves)
@@ -102,7 +116,9 @@ class Replayer:
                 f"({rec.manifest['topology'][:12]}... vs "
                 f"{_topology_fingerprint()[:12]}...)")
         in_tree, out_tree = pickle.loads(rec.trees)
-        exe = se.deserialize_and_load(rec.payload, in_tree, out_tree)
+        exe = se.deserialize_and_load(
+            rec.payload, in_tree, out_tree,
+            execution_devices=_execution_devices(rec.manifest))
         nm = name or rec.manifest["name"]
         # manifest aval check happens HERE, once: the signature is the
         # cache key, so every execute() validates by construction

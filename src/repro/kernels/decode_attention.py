@@ -1,10 +1,12 @@
 """Single-token GQA decode attention vs a long KV cache (Pallas TPU).
 
 Grid (B, Hkv, nW): W (cache) blocks iterate innermost, carrying online
-softmax state in VMEM scratch.  The q tile is [G, hd] (all G query heads of
-one KV group), so the MXU contraction is [G,hd]x[hd,blk] — for G>=8 this
-keeps the MXU busy even at batch 1, which is the long-context decode cell's
-regime.  VMEM: one [blk_w, hd] K tile + V tile + [G, blk_w] scores.
+softmax state in VMEM scratch.  The per-row lengths are scalar-prefetched
+into SMEM, so blocks past a row's length skip their FLOPs.  The q tile is
+[G, hd] (all G query heads of one KV group), so the MXU contraction is
+[G,hd]x[hd,blk] — for G>=8 this keeps the MXU busy even at batch 1, which
+is the long-context decode cell's regime.  VMEM: one [blk_w, hd] K tile +
+V tile + [G, blk_w] scores.
 """
 from __future__ import annotations
 
@@ -13,14 +15,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.flash_attention import pl_scratch
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
             scale, blk_w, n_w):
+    b = pl.program_id(0)
     iw = pl.program_id(2)
 
     @pl.when(iw == 0)
@@ -29,7 +31,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    length = len_ref[0]
+    length = len_ref[b]
     base = iw * blk_w
 
     @pl.when(base < length)
@@ -58,7 +60,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
-                     blk_w=256, interpret=True):
+                     blk_w=256, interpret: bool):
     """q [B,H,hd]; caches [B,W,Hkv,hd]; lengths [B] -> [B,H,hd]."""
     B, H, hd = q.shape
     W, Hkv = k_cache.shape[1], k_cache.shape[2]
@@ -74,17 +76,23 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
     kernel = functools.partial(_kernel, scale=scale, blk_w=blk_w, n_w=n_w)
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv, n_w),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, iw: (b,)),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, iw: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, blk_w, hd), lambda b, h, iw: (b, h, iw, 0)),
-            pl.BlockSpec((1, 1, blk_w, hd), lambda b, h, iw: (b, h, iw, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, iw: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, n_w),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, hd),
+                             lambda b, h, iw, lens: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, blk_w, hd),
+                             lambda b, h, iw, lens: (b, h, iw, 0)),
+                pl.BlockSpec((1, 1, blk_w, hd),
+                             lambda b, h, iw, lens: (b, h, iw, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, hd),
+                                   lambda b, h, iw, lens: (b, h, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((G,), jnp.float32),
+                            pltpu.VMEM((G,), jnp.float32),
+                            pltpu.VMEM((G, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
-        scratch_shapes=[pl_scratch((G,)), pl_scratch((G,)),
-                        pl_scratch((G, hd))],
         interpret=interpret,
     )(lengths.astype(jnp.int32), qg, kt, vt)
     return out.reshape(B, H, hd)

@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -78,7 +79,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
-                    blk_q=128, blk_k=128, interpret=True):
+                    blk_q=128, blk_k=128, interpret: bool):
     """q [B,Sq,H,hd]; k,v [B,Sk,Hkv,hd] -> [B,Sq,H,hd]."""
     B, Sq, H, hd = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
@@ -111,18 +112,11 @@ def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
         out_specs=pl.BlockSpec((1, blk_q, hd), lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
         scratch_shapes=[
-            pl_scratch((blk_q,)),
-            pl_scratch((blk_q,)),
-            pl_scratch((blk_q, hd)),
+            pltpu.VMEM((blk_q,), jnp.float32),
+            pltpu.VMEM((blk_q,), jnp.float32),
+            pltpu.VMEM((blk_q, hd), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
 
-
-def pl_scratch(shape):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover - interpret fallback
-        return pl.MemorySpace.ANY

@@ -11,8 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.flash_attention import pl_scratch
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_sc, *, n_d):
@@ -31,7 +30,8 @@ def _kernel(x_ref, w_ref, o_ref, acc_sc, *, n_d):
         o_ref[0] = acc_sc[...].astype(o_ref.dtype)
 
 
-def moe_gmm(x, w, *, blk_c=128, blk_f=128, blk_d=128, interpret=True):
+def moe_gmm(x, w, *, blk_c=128, blk_f=128, blk_d=128,
+            interpret: bool):
     E, C, D = x.shape
     F = w.shape[-1]
     blk_c, blk_f, blk_d = min(blk_c, C), min(blk_f, F), min(blk_d, D)
@@ -46,6 +46,6 @@ def moe_gmm(x, w, *, blk_c=128, blk_f=128, blk_d=128, interpret=True):
         ],
         out_specs=pl.BlockSpec((1, blk_c, blk_f), lambda e, c, f, d: (e, c, f)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
-        scratch_shapes=[pl_scratch((blk_c, blk_f))],
+        scratch_shapes=[pltpu.VMEM((blk_c, blk_f), jnp.float32)],
         interpret=interpret,
     )(x, w)

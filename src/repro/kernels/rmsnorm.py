@@ -21,7 +21,8 @@ def _kernel(x_ref, s_ref, o_ref, *, eps):
                   s_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def rmsnorm(x, scale, *, eps: float = 1e-5, blk: int = 256, interpret=True):
+def rmsnorm(x, scale, *, eps: float = 1e-5, blk: int = 256,
+            interpret: bool):
     """x [..., D]; scale [D]."""
     orig_shape = x.shape
     D = x.shape[-1]

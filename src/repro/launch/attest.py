@@ -68,7 +68,10 @@ def main(argv=None):
     if args.verify:
         return _verify(args.verify, key)
 
+    # the demo compiles; the offline verification above stays JAX-free
     from repro.api import Workspace
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ws = Workspace(registry=":memory:", key=key, net=args.net)
     wl = ws.workload(args.arch, cache_len=args.cache_len,
                      block_k=args.block_k, batch=2, seq=args.seq)

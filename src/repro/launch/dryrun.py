@@ -1,16 +1,18 @@
-# ruff: noqa: E402
-# (XLA_FLAGS must be set before any jax-importing module is touched)
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count on first init).  The dry-run proves the distribution config is
-# coherent: every (arch x shape) cell must lower AND compile for the 16x16
-# single-pod mesh and the 2x16x16 multi-pod mesh.
+"""Dry-run: every (arch x shape) cell must lower AND compile for the 16x16
+single-pod mesh and the 2x16x16 multi-pod mesh, on 512 virtual CPU devices.
 
+    PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-3b
+
+``main`` asks for the 512 host devices before JAX creates its CPU backend,
+so the flag holds only when this module runs as the program.  For a
+program sized for one real chip, compile against a described TPU topology
+instead (``tests/test_tpu_compile.py``).
+"""
 import argparse
 import dataclasses
 import glob
 import json
+import os
 import shutil
 import tempfile
 import time
@@ -23,8 +25,7 @@ from repro.analysis import hlo as hlo_an
 from repro.analysis import roofline as rf
 from repro.configs import (ARCHS, SHAPES, cell_applicable, get_config,
                            input_specs)
-from repro import compat
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import model as M
 from repro.sharding import rules_for, shardings_for, spec
 from repro.training import steps as ST
@@ -111,7 +112,7 @@ def run_cell(arch, shape_name, multi_pod, overrides=None, keep_text=False):
             cfg, shape_name, mesh, overrides)
         t0 = time.time()
         dump_dir = tempfile.mkdtemp(prefix="hlo_spmd_")
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                              donate_argnums=donate)
             lowered = jitted.lower(*args)
@@ -122,7 +123,7 @@ def run_cell(arch, shape_name, multi_pod, overrides=None, keep_text=False):
                 "xla_dump_hlo_pass_re": "spmd-partitioning"})
             t_compile = time.time() - t0
         mem = compiled.memory_analysis()
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         text = compiled.as_text()
         # dtype-true (bf16) post-SPMD module for the roofline byte counts;
         # the final scheduled module inflates bf16 to f32 (CPU legalization)
@@ -166,6 +167,8 @@ def run_cell(arch, shape_name, multi_pod, overrides=None, keep_text=False):
 
 
 def main():
+    # before the first device query: the CPU backend reads this once
+    jax.config.update("jax_num_cpu_devices", 512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

@@ -21,12 +21,16 @@ import os
 
 from repro.api import Workspace
 from repro.core import PROFILES
+from repro.launch.cache import enable_compile_cache
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-shrunk widths (--no-smoke: the "
+                         "published widths)")
     ap.add_argument("--devices", type=int, default=4)
     ap.add_argument("--net", default="wifi",
                     help="comma list of link profiles, round-robin over "
@@ -50,7 +54,11 @@ def main(argv=None):
     ap.add_argument("--no-share-history", action="store_true",
                     help="cold speculator per session (the serial "
                          "baseline's behavior)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     registry = args.registry if args.registry else ":memory:"
     if args.registry:
@@ -91,4 +99,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

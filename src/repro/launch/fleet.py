@@ -19,6 +19,7 @@ import time
 from repro.api import Workspace
 from repro.core import PROFILES
 from repro.fleet import POLICIES, OpenLoopTraffic, TenantMix
+from repro.launch.cache import enable_compile_cache
 
 # registry prefill recordings pin the prompt shape; live fleets may vary
 REC_SEQ = 16
@@ -92,4 +93,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -4,16 +4,20 @@
 importing this module never touches jax device state.  Single pod =
 16x16 = 256 chips (v5e pod); multi-pod = 2 pods = 512 chips with a leading
 'pod' axis (data-parallel across the DCI).
-
-Version differences (AxisType / set_mesh) are absorbed by ``repro.compat``.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh, set_mesh  # re-export for launchers
+__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh"]
 
-__all__ = ["make_mesh", "set_mesh", "make_production_mesh", "make_host_mesh"]
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types: shardings come from the
+    logical-axis rules and XLA propagation, not from explicit types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

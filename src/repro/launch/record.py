@@ -26,15 +26,19 @@ import os
 from repro.api import (Workspace, build_step, format_session_report,
                        recording_name, static_meta_for)
 from repro.core import PROFILES
+from repro.launch.cache import enable_compile_cache
 
 __all__ = ["build_step", "static_meta_for", "recording_name",
            "format_session_report", "main"]
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-shrunk widths (--no-smoke: the "
+                         "published widths)")
     ap.add_argument("--kinds", default="prefill,decode")
     ap.add_argument("--out", default="/tmp/recordings")
     ap.add_argument("--registry", default=None,
@@ -61,7 +65,11 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=1,
                     help="> 1 fans the kinds out across a device pool "
                          "(campaign API) instead of recording serially")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     registry = None
     if not args.no_registry:
@@ -117,4 +125,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -33,6 +33,7 @@ import numpy as np
 from repro.api import Workspace, stream_kwargs
 from repro.configs import get_config, smoke_shrink
 from repro.core import PROFILES, NetworkEmulator
+from repro.launch.cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving.engine import Engine
 
@@ -128,13 +129,16 @@ def _serve_multi(args, netem):
     return outs, sched
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--streams", default="",
                     help="comma-separated archs to serve CONCURRENTLY "
                          "through one Scheduler (multi-tenant mode)")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-shrunk widths (--no-smoke: the "
+                         "published widths)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--slots", type=int, default=4)
@@ -152,7 +156,11 @@ def main(argv=None):
                     choices=["none"] + sorted(PROFILES),
                     help="emulated network profile for registry fetches")
     ap.add_argument("--key", default="cody-demo-key")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     netem = None
     if args.net != "none":
@@ -197,4 +205,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ import argparse
 
 from repro.api import Workspace
 from repro.core import PROFILES
+from repro.launch.cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -81,4 +82,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, smoke_shrink
 from repro.data.pipeline import Prefetcher, SyntheticLM
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.runtime.checkpoint import CheckpointStore
 from repro.runtime.elastic import reshard_state
@@ -27,10 +28,13 @@ from repro.training.grad_compress import make_ef_int8_transform
 from repro.training.optimizer import AdamWConfig, init_opt_state
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-shrunk widths (--no-smoke: the "
+                         "published widths)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -41,7 +45,11 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -67,7 +75,7 @@ def main(argv=None):
         start_step = manifest["step"]
         print(f"resumed from step {start_step} on {len(jax.devices())} devices")
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(train_step, donate_argnums=(0,))
         loader = Prefetcher(data)
         t0 = time.time()
@@ -92,4 +100,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

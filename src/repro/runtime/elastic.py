@@ -32,11 +32,9 @@ def make_elastic_mesh(n_devices: Optional[int] = None, prefer_model: int = 16):
     devs = jax.devices()[:n_devices] if n_devices else jax.devices()
     shape = choose_mesh_shape(len(devs), prefer_model)
     import numpy as _np
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
     return jax.sharding.Mesh(
-        _np.asarray(devs).reshape(shape), ("data", "model"), **kw)
+        _np.asarray(devs).reshape(shape), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def reshard_state(state_np, axes_tree, mesh, mode: str = "train"):

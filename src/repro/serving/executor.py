@@ -283,7 +283,10 @@ class StreamExecutor:
 
     def _scatter_caches(self, new_caches, slots_arr: np.ndarray):
         """Vectorized scatter of a prefilled group into the slot caches:
-        one indexed ``.set`` per cache leaf (not per request per leaf)."""
+        one indexed ``.set`` per cache leaf (not per request per leaf).
+        The caches keep their placement: the decode step was compiled for
+        it, and the prefill output it is mixed with may be laid out
+        differently (replicated across a data-parallel mesh)."""
         flat_c, td = jax.tree.flatten(self.caches)
         flat_n = jax.tree.leaves(new_caches)
         axes = self._batch_axes or [0] * len(flat_c)
@@ -292,6 +295,7 @@ class StreamExecutor:
         for c, n, ax in zip(flat_c, flat_n, axes):
             sel = (slice(None),) * ax + (idx,)
             out_leaves.append(c.at[sel].set(n.astype(c.dtype)))
+        out_leaves = jax.device_put(out_leaves, [c.sharding for c in flat_c])
         self.caches = jax.tree.unflatten(td, out_leaves)
 
     def _prefill_into_slot(self, req: Request, slot: int):

@@ -136,8 +136,16 @@ def tree_shardings(axes_tree, mesh: Mesh, rules: dict):
 
 
 def constrain(x, axes: Tuple[Optional[str], ...], rules: dict):
-    """with_sharding_constraint by logical axes (no-op outside jit/mesh)."""
+    """with_sharding_constraint by logical axes (no-op outside jit/mesh).
+
+    Under a mesh, a dim the mapped axes do not divide is replicated, as in
+    ``shardings_for``: a batch of 1 on a data-parallel mesh stays whole
+    instead of being split unevenly, which would let the partitioner split
+    the contractions and all-reduce bf16 partial sums."""
+    mesh = jax.sharding.get_abstract_mesh()
+    mesh_shape = None if mesh.empty else dict(mesh.shape)
     try:
-        return jax.lax.with_sharding_constraint(x, spec(axes, rules))
+        return jax.lax.with_sharding_constraint(
+            x, spec(axes, rules, x.shape, mesh_shape))
     except (ValueError, RuntimeError):
         return x

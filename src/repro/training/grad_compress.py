@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -84,5 +83,5 @@ def compressed_psum(x, mesh, axis: str = "data"):
         return full[:xs.size].reshape(xs.shape)
 
     spec = P(*[None] * x.ndim)
-    return shard_map(inner, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)(x)
+    return jax.shard_map(inner, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(x)

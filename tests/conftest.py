@@ -6,18 +6,3 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # NOTE: no XLA_FLAGS here on purpose — smoke tests must see 1 device;
 # multi-device tests spawn subprocesses that set their own flags.
 
-# Optional-hypothesis shim shared by test modules: property tests skip when
-# hypothesis is absent (this container), run for real in CI.
-try:
-    from hypothesis import given, settings, strategies as st   # noqa: F401
-except ImportError:
-    import pytest
-
-    class _St:
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    st = _St()
-    settings = lambda *a, **k: (lambda fn: fn)
-    given = lambda *a, **k: pytest.mark.skip(
-        reason="hypothesis not installed")

@@ -2,8 +2,11 @@
 ``Workload``, backward-compat of the ``launch.record``/``launch.serve``
 shims (byte-identical recordings, identical serve stats), and the misuse
 errors that keep unverified bytes away from ``pickle.loads``."""
+import importlib
 import os
 import pickle
+import subprocess
+import sys
 import tempfile
 
 import jax
@@ -179,3 +182,38 @@ def test_unsigned_fetch_rejected_before_any_unpickle():
     with pytest.raises(TamperedRecordingError):
         wl.fetch("prefill")
     assert SIDE_EFFECTS == []                 # the pickle never executed
+
+
+# ---------------------------------------------------- entry points ----
+@pytest.mark.parametrize("cli", ["serve", "record", "train", "fanout"])
+def test_cli_smoke_flag_reaches_published_widths(cli):
+    """``--smoke`` is the default and ``--no-smoke`` turns it off, so the
+    command-line entry points can run a config at its published widths."""
+    parser = importlib.import_module(f"repro.launch.{cli}").build_parser()
+    assert parser.parse_args([]).smoke is True
+    assert parser.parse_args(["--smoke"]).smoke is True
+    assert parser.parse_args(["--no-smoke"]).smoke is False
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_directory(tmp_path, env_dir):
+    """The entry points' compile cache lives where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else at ``<checkout>/.jax_cache``
+    — a fixed path, so a second run finds the first run's entries.
+    Checked in a child: the cache is process-global and tests keep it
+    off."""
+    repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(repo, ".jax_cache")
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
+    code = ("import sys, jax\n"
+            f"sys.path.insert(0, {os.path.join(repo, 'src')!r})\n"
+            "from repro.launch.cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want]

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CELLULAR, WIFI, CommitQueue, DeltaSync,
                         HistorySpeculator, MispredictError, NetworkEmulator,

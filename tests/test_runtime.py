@@ -98,7 +98,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, jax, jax.numpy as jnp, numpy as np
 sys.path.insert(0, sys.argv[1])
 from repro.configs import get_config, smoke_shrink
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.runtime.checkpoint import CheckpointStore
 from repro.runtime.elastic import make_elastic_mesh, reshard_state
@@ -115,7 +115,7 @@ rules = rules_for("train", mesh.axis_names)
 step_fn = ST.make_train_step(cfg, rules, AdamWConfig(warmup_steps=1, decay_steps=4))
 batch = {"tokens": jnp.ones((4, 16), jnp.int32),
          "labels": jnp.ones((4, 16), jnp.int32)}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     state, metrics = jax.jit(step_fn, donate_argnums=(0,))(state, batch)
 assert np.isfinite(float(metrics["loss"]))
 print("ELASTIC_OK", float(metrics["loss"]))
@@ -167,10 +167,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, jax, jax.numpy as jnp, numpy as np
 sys.path.insert(0, sys.argv[1])
 from repro.training.grad_compress import compressed_psum
-from repro import compat
-mesh = compat.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 x = jnp.linspace(-1.0, 1.0, 4096).reshape(64, 64)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = compressed_psum(x, mesh, "data")
 want = x * 8
 err = float(jnp.max(jnp.abs(got - want))) / float(jnp.max(jnp.abs(want)))

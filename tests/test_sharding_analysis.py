@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.analysis.hlo import _shape_bytes, _wire_bytes, analyze
 from repro.sharding import rules_for, spec

@@ -107,12 +107,12 @@ import sys
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
 from repro.configs import get_config, smoke_shrink, input_specs
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.sharding import rules_for, shardings_for
 from repro.models import model as M
 from repro.training import steps as ST
 from repro.analysis.hlo import analyze
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 for arch in ("qwen2.5-3b", "zamba2-1.2b"):
     cfg = smoke_shrink(get_config(arch), vocab_size=512)
     rules = rules_for("train", mesh.axis_names)
@@ -121,7 +121,7 @@ for arch in ("qwen2.5-3b", "zamba2-1.2b"):
     batch = {"tokens": jax.ShapeDtypeStruct((8, 64), jnp.int32),
              "labels": jax.ShapeDtypeStruct((8, 64), jnp.int32)}
     st_sh = shardings_for(ST.train_state_axes(cfg), state, mesh, rules)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(fn, in_shardings=(st_sh, None),
                     donate_argnums=(0,)).lower(state, batch).compile()
     cost = analyze(c.as_text(), 8)
