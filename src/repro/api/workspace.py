@@ -99,7 +99,9 @@ class Workspace:
         # trace=True builds a Tracer on the workspace link's virtual clock
         # (constant 0 base when there is no link — scoped components rebase
         # their own emulators); trace=False leaves the falsy NULL tracer so
-        # every traced() call site is a single truthiness check
+        # every traced() call site is a single truthiness check.  A Tracer
+        # is used as given: Tracer(annotate=True) puts the spans on the
+        # host clock and into the JAX profiler's trace.
         if isinstance(trace, Tracer):
             self.tracer = trace
         elif trace:

@@ -1,4 +1,4 @@
-"""Virtual-time tracing — the evidence layer for every headline number.
+"""Tracing — the evidence layer for every headline number.
 
 Every claim this repro makes (95% fewer recording delays, replay 25%
 faster than native, frontier-only host syncs) is an *attribution* claim
@@ -10,12 +10,12 @@ trace-event JSON that Perfetto / ``chrome://tracing`` loads directly.
 
 Design constraints, in order:
 
-  * **Deterministic.**  Two traced runs of the same workload produce
-    byte-identical traces once wall timestamps are stripped
+  * **Deterministic.**  Two virtual-clock traces of the same workload
+    are byte-identical once wall timestamps are stripped
     (``to_json(strip_wall=True)``) — the replay-side analogue of the
     bit-exactness flags the benchmarks pin.  Nothing in here calls a
     nondeterministic source except ``time.time()`` for the secondary
-    wall fields.
+    wall fields (and, in annotate mode below, its host clock).
   * **Zero-cost when off.**  ``NULL`` (a falsy ``NullTracer``) is what
     every component holds by default; call sites guard hot paths with
     ``if tracer:`` or the ``traced()`` helper.  Tracing never mutates an
@@ -32,18 +32,29 @@ Design constraints, in order:
 Event vocabulary (Chrome trace phases): ``X`` complete spans (duration =
 virtual time elapsed inside), ``i`` instants, ``C`` counter samples.
 Tracks (one Perfetto thread lane each): ``record``, ``replay``,
-``registry``, ``serve.<stream>``, ``sched``.
+``registry``, ``serve.<stream>``, ``sched``, ``host``.
+
+**Annotate mode** (``Tracer(annotate=True)``) stamps events on the host's
+monotonic clock (``time.perf_counter``) instead, and also writes every
+span into the JAX profiler's trace as a ``jax.profiler.TraceAnnotation``
+(its args become the event's stats; an instant is a zero-length one), so
+the serving path's spans sit on one clock with the device's operations
+whenever a profiler trace is running.  A virtual clock cannot be placed
+on that clock, so ``clock_scope`` is a no-op there.  jax is imported only
+when an annotating tracer is built.
 """
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import time
 from typing import Callable, List, Optional
 
 
 class _NullSpan:
-    """Reusable no-op context manager (the body still runs)."""
+    """Reusable no-op context manager (the body still runs).  It stands in
+    for a span's args too: keys set on it are dropped."""
 
     __slots__ = ()
 
@@ -52,6 +63,9 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def __setitem__(self, key, value):
+        pass
 
 
 _NULL_SPAN = _NullSpan()
@@ -83,6 +97,9 @@ class NullTracer:
     def counter(self, name, value, track="main") -> None:
         pass
 
+    def watch_gc(self, track="host"):
+        return _NULL_SPAN
+
     def mark(self) -> int:
         return 0
 
@@ -105,14 +122,31 @@ class Tracer:
     base clock is a constant 0 — spans still nest and count, with wall
     time as the only moving timestamp (kept out of the deterministic
     export).
+
+    ``annotate=True`` is the host-clock mode (module docstring): the
+    clock is ``time.perf_counter`` and every span and instant is also a
+    profiler ``TraceAnnotation``.
     """
 
-    def __init__(self, clock: Optional[Callable[[], float]] = None):
+    def __init__(self, clock: Optional[Callable[[], float]] = None, *,
+                 annotate: bool = False):
+        if annotate and clock is not None:
+            raise ValueError("an annotating tracer runs on the host's "
+                             "perf_counter clock; it takes no clock")
         self.events: List[dict] = []
+        self._annotation = None         # TraceAnnotation in annotate mode
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+            clock = time.perf_counter
         self._clocks: List[Callable[[], float]] = [
             clock if clock is not None else (lambda: 0.0)]
         self._hwm = 0.0                 # latest virtual timestamp emitted
         self._t0_wall = time.time()
+
+    @property
+    def annotate(self) -> bool:
+        return self._annotation is not None
 
     def __bool__(self) -> bool:
         return True
@@ -126,8 +160,8 @@ class Tracer:
         """Stamp events inside this scope with ``netem``'s virtual clock,
         rebased onto the trace high-water mark (sessions with private
         emulators lay out sequentially instead of overlapping at 0).
-        ``netem=None`` is a no-op scope."""
-        if netem is None:
+        ``netem=None`` and annotate mode are no-op scopes."""
+        if netem is None or self.annotate:
             yield self
             return
         base = max(self.now(), self._hwm) - float(netem.virtual_time_s)
@@ -147,12 +181,19 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, track: str = "main", **args):
         """A complete span: virtual-time begin/duration measured around
-        the body; wall time recorded as secondary metadata."""
+        the body; wall time recorded as secondary metadata.  The body gets
+        ``args``: a key it sets there is recorded with the span."""
+        ann = self._annotation(name) if self._annotation else None
+        if ann is not None:
+            ann.__enter__()
         t0 = self.now()
         w0 = time.time()
         try:
-            yield self
+            yield args
         finally:
+            if ann is not None:
+                ann.set_metadata(**args)
+                ann.__exit__(None, None, None)
             self._emit({"name": name, "ph": "X", "track": track,
                         "ts": t0, "dur": self.now() - t0,
                         "wall_s": w0 - self._t0_wall,
@@ -160,6 +201,9 @@ class Tracer:
                         "args": args})
 
     def instant(self, name: str, track: str = "main", **args) -> None:
+        if self._annotation:
+            with self._annotation(name, **args):
+                pass
         self._emit({"name": name, "ph": "i", "track": track,
                     "ts": self.now(),
                     "wall_s": time.time() - self._t0_wall,
@@ -170,6 +214,29 @@ class Tracer:
                     "ts": self.now(), "value": float(value),
                     "wall_s": time.time() - self._t0_wall,
                     "args": {}})
+
+    @contextlib.contextmanager
+    def watch_gc(self, track: str = "host"):
+        """Record every garbage collection inside the scope as a
+        ``host.gc`` span (args ``generation`` and ``collected``): a
+        collection is a host pause the device may sit idle through."""
+        open_spans: list = []
+
+        def on_gc(phase, info):
+            if phase == "start":
+                span = self.span("host.gc", track,
+                                 generation=info["generation"])
+                open_spans.append((span, span.__enter__()))
+            elif open_spans:
+                span, args = open_spans.pop()
+                args["collected"] = info["collected"]
+                span.__exit__(None, None, None)
+
+        gc.callbacks.append(on_gc)
+        try:
+            yield self
+        finally:
+            gc.callbacks.remove(on_gc)
 
     def mark(self) -> int:
         """Event-index bookmark; pass as ``since=`` to scope analysis to
@@ -262,7 +329,8 @@ class Tracer:
                  "args": {"name": track}}
                 for track, tid in sorted(tids.items(), key=lambda kv: kv[1])]
         return {"traceEvents": meta + out, "displayTimeUnit": "ms",
-                "metadata": {"clock": "virtual"}}
+                "metadata": {"clock": "host" if self.annotate
+                             else "virtual"}}
 
     def to_json(self, strip_wall: bool = False) -> str:
         return json.dumps(self.chrome_trace(strip_wall=strip_wall),

@@ -48,6 +48,13 @@ class PreemptionUnsupportedError(RuntimeError):
 
 @dataclasses.dataclass
 class Request:
+    """One request and its stamps, all on ``time.perf_counter()`` (0.0 =
+    not yet): ``submit_t``; ``admit_t``, its first slot allocation;
+    ``prefilled_t``, its first prefill's token read back to the host;
+    ``first_t``, the frontier drain that first committed a token;
+    ``finish_t``, its retirement.  A request that ends inside the drain
+    that first commits it is retired before that commit, so there
+    ``finish_t`` is a little earlier than ``first_t``."""
     rid: int
     prompt: List[int]
     max_new: int
@@ -56,6 +63,9 @@ class Request:
     done: bool = False
     failed: bool = False          # dropped (e.g. prefix outgrew the cache)
     submit_t: float = 0.0
+    admit_t: float = 0.0
+    prefilled_t: float = 0.0
+    first_t: float = 0.0
     finish_t: float = 0.0
 
     def prefix(self) -> List[int]:
@@ -147,7 +157,7 @@ class StreamExecutor:
         rid = self._rid
         self._rid += 1
         self.requests[rid] = Request(rid, list(prompt), max_new,
-                                     submit_t=time.time())
+                                     submit_t=time.perf_counter())
         self.pending.append(rid)
         return rid
 
@@ -202,10 +212,18 @@ class StreamExecutor:
             if budget <= 0:
                 self.stats["admissions_deferred"] += 1
                 return
+        with traced(self.tracer, "executor.admit", self.track,
+                    rid=self.pending[0]) as span:
+            span["n"] = self._admit_group(budget)
+
+    def _admit_group(self, budget) -> int:
+        """Drain if blocks are in flight, then allocate slots to pending
+        requests and prefill them; returns how many were admitted."""
         if self.inflight:
             # admission changes the decode batch and re-seeds the device
             # chain from host metastate — which is STALE while blocks are
             # in flight (tails apply at the frontier).  Drain first.
+            self.stats["admission_drains"] += 1
             self.frontier.drain(self)
         group = []
         while self.pending and (budget is None or len(group) < budget):
@@ -217,16 +235,17 @@ class StreamExecutor:
                 self.pending.popleft()
                 req.done = True
                 req.failed = True
-                req.finish_t = time.time()
+                req.finish_t = time.perf_counter()
                 self.stats["capacity_dropped"] += 1
                 continue
             slot = self.slots.alloc(rid, len(req.prefix()))
             if slot is None:
                 break
             self.pending.popleft()
+            req.admit_t = req.admit_t or time.perf_counter()
             group.append((req, slot))
         if not group:
-            return
+            return 0
         self.reset_device_chain()          # host metastate changes below
         if not self.channel.supports_batched_prefill:
             for req, slot in group:
@@ -235,6 +254,7 @@ class StreamExecutor:
             for plen, members in sorted(self._bucketize(group).items()):
                 self._prefill_group(members, plen)
         self.stats["admitted"] += len(group)
+        return len(group)
 
     def _bucketize(self, group):
         """Group (request, slot) pairs by padded prompt length so each
@@ -248,11 +268,13 @@ class StreamExecutor:
             buckets.setdefault(padded, []).append((req, slot))
         return buckets
 
-    def _seed_slot(self, req: Request, slot: int, predicted_first: int):
+    def _seed_slot(self, req: Request, slot: int, predicted_first: int,
+                   now: float):
         """Install a freshly prefilled request's next decode input.  For a
         resumed request the model re-predicts ``generated[-1]`` (greedy
         decode is deterministic), so the committed tail stays authoritative
         and nothing is appended twice."""
+        req.prefilled_t = req.prefilled_t or now
         if req.generated:
             self._slot_tokens[slot] = req.generated[-1]
         else:
@@ -274,8 +296,9 @@ class StreamExecutor:
             out, caches = self.channel.batched_prefill(
                 self.params, jnp.asarray(toks), jnp.asarray(lens))
             firsts = np.asarray(out["next_tokens"])
+            now = time.perf_counter()
             for row, (req, slot) in enumerate(members):
-                self._seed_slot(req, slot, int(firsts[row]))
+                self._seed_slot(req, slot, int(firsts[row]), now)
             self._scatter_caches(caches, np.array([s for _, s in members]))
             if self.netem is not None:
                 self.netem.round_trip()  # ONE synchronous commit per bucket
@@ -287,16 +310,19 @@ class StreamExecutor:
         The caches keep their placement: the decode step was compiled for
         it, and the prefill output it is mixed with may be laid out
         differently (replicated across a data-parallel mesh)."""
-        flat_c, td = jax.tree.flatten(self.caches)
-        flat_n = jax.tree.leaves(new_caches)
-        axes = self._batch_axes or [0] * len(flat_c)
-        idx = jnp.asarray(slots_arr)
-        out_leaves = []
-        for c, n, ax in zip(flat_c, flat_n, axes):
-            sel = (slice(None),) * ax + (idx,)
-            out_leaves.append(c.at[sel].set(n.astype(c.dtype)))
-        out_leaves = jax.device_put(out_leaves, [c.sharding for c in flat_c])
-        self.caches = jax.tree.unflatten(td, out_leaves)
+        with traced(self.tracer, "executor.scatter", self.track,
+                    n=len(slots_arr)):
+            flat_c, td = jax.tree.flatten(self.caches)
+            flat_n = jax.tree.leaves(new_caches)
+            axes = self._batch_axes or [0] * len(flat_c)
+            idx = jnp.asarray(slots_arr)
+            out_leaves = []
+            for c, n, ax in zip(flat_c, flat_n, axes):
+                sel = (slice(None),) * ax + (idx,)
+                out_leaves.append(c.at[sel].set(n.astype(c.dtype)))
+            out_leaves = jax.device_put(out_leaves,
+                                        [c.sharding for c in flat_c])
+            self.caches = jax.tree.unflatten(td, out_leaves)
 
     def _prefill_into_slot(self, req: Request, slot: int):
         """Per-request path: exact shapes (required for recorded prefill
@@ -305,8 +331,8 @@ class StreamExecutor:
                     rid=req.rid, prefix_len=len(req.prefix())):
             batch = {"tokens": jnp.asarray([req.prefix()], jnp.int32)}
             out, caches = self.channel.prefill(self.params, batch)
-            self._seed_slot(req, slot,
-                            int(np.asarray(out["next_tokens"])[0]))
+            first = int(np.asarray(out["next_tokens"])[0])
+            self._seed_slot(req, slot, first, time.perf_counter())
             self._scatter_caches(caches, np.array([slot]))
             if self.netem is not None:
                 self.netem.round_trip()  # prefill is a synchronous commit
@@ -415,7 +441,7 @@ class StreamExecutor:
                     req.generated = req.generated[:int(eos[0]) + 1]
             req.generated = req.generated[:req.max_new]
             req.done = True
-            req.finish_t = time.time()
+            req.finish_t = time.perf_counter()
             self.slots.release(i)
             self.reset_device_chain()          # slot table changed
             self.stats["retired"] += 1
@@ -428,9 +454,6 @@ class StreamExecutor:
                 self.metrics.counter(
                     "tokens_generated", stream=self.name).inc(
                         len(req.generated))
-            if self.tracer:
-                self.tracer.instant("request.done", self.track, rid=req.rid,
-                                    tokens=len(req.generated))
 
     def outputs(self) -> Dict[int, List[int]]:
         return {rid: r.generated for rid, r in self.requests.items()}

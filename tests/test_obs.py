@@ -146,6 +146,102 @@ def test_null_tracer_is_falsy_noop():
     assert len(tr.events) == 1
 
 
+def test_span_records_args_set_in_its_body():
+    clk = FakeClock()
+    tr = Tracer(clock=clk)
+    with traced(tr, "admit", "t", rid=3) as args:
+        args["n"] = 2
+    assert tr.spans("t")[0]["args"] == {"rid": 3, "n": 2}
+    with traced(NULL, "admit", "t", rid=3) as args:
+        args["n"] = 2                       # dropped, no error
+    assert NULL.events == ()
+
+
+def test_null_tracer_watch_gc_records_nothing():
+    import gc
+    callbacks = list(gc.callbacks)
+    with NULL.watch_gc():
+        gc.collect()
+    assert NULL.events == () and gc.callbacks == callbacks
+
+
+def test_watch_gc_records_each_collection_and_unhooks():
+    import gc
+    clk = FakeClock()
+    tr = Tracer(clock=clk)
+    callbacks = list(gc.callbacks)
+    with tr.watch_gc():
+        gc.collect(1)
+    gc.collect()                            # outside the scope: not seen
+    assert gc.callbacks == callbacks
+    spans = tr.spans("host")
+    assert [s["name"] for s in spans] == ["host.gc"]
+    assert spans[0]["args"]["generation"] == 1
+    assert spans[0]["args"]["collected"] >= 0
+
+
+def test_annotate_mode_runs_on_the_host_clock():
+    import time
+    with pytest.raises(ValueError):
+        Tracer(clock=FakeClock(), annotate=True)
+    tr = Tracer(annotate=True)
+    assert tr.annotate and not Tracer().annotate
+    t0 = time.perf_counter()
+    with tr.clock_scope(NetworkEmulator(WIFI)), tr.span("s", "t", k=1):
+        time.sleep(0.002)
+    tr.instant("i", "t")
+    span, inst = tr.events
+    assert t0 <= span["ts"] and span["dur"] >= 0.002
+    assert span["ts"] + span["dur"] <= inst["ts"] <= time.perf_counter()
+    assert tr.chrome_trace()["metadata"]["clock"] == "host"
+
+
+def test_annotate_mode_writes_spans_into_the_profiler_trace(tmp_path):
+    """Spans, instants and ``host.gc`` reach a CPU profiler trace under
+    their bare names, nested, with their args as event stats."""
+    import gc
+    import glob
+    from jax.profiler import ProfileData
+    tr = Tracer(annotate=True)
+    jax.profiler.start_trace(str(tmp_path))
+    with tr.watch_gc():
+        with traced(tr, "executor.admit", "serve.s", rid=3) as args:
+            with tr.span("prefill.dispatch", "serve.s", rid=3):
+                jnp.ones(4).block_until_ready()
+            tr.instant("frontier.mispredict", "serve.s", dropped=2)
+            gc.collect(0)
+            args["n"] = 1
+    jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    seen, gcs = {}, []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    ev = (e.start_ns, e.duration_ns,
+                          {k: v for k, v in e.stats})
+                    if e.name == "host.gc":
+                        gcs.append(ev)
+                    elif e.name in ("executor.admit", "prefill.dispatch",
+                                    "frontier.mispredict"):
+                        seen[e.name] = ev
+    assert seen["executor.admit"][2] == {"rid": 3, "n": 1}
+    assert seen["prefill.dispatch"][2] == {"rid": 3}
+    assert seen["frontier.mispredict"][2] == {"dropped": 2}
+    a0, adur, _ = seen["executor.admit"]
+    inside = lambda ev: a0 <= ev[0] and ev[0] + ev[1] <= a0 + adur
+    assert inside(seen["prefill.dispatch"])
+    assert inside(seen["frontier.mispredict"])
+    assert seen["frontier.mispredict"][1] < 1e5        # zero-length
+    # the forced collection (the library may collect on its own too)
+    assert any(inside(ev) and ev[2]["generation"] == 0 for ev in gcs)
+    # the in-memory events keep the same spans on the same clock
+    names = [e["name"] for e in tr.events if e["name"] != "host.gc"]
+    assert names == ["prefill.dispatch", "frontier.mispredict",
+                     "executor.admit"]
+    assert len(tr.spans("host")) == len(gcs)
+
+
 def test_summary_orders_by_virtual_time():
     clk = FakeClock()
     tr = Tracer(clock=clk)

@@ -22,7 +22,7 @@ def _cfg(arch="cody-mnist"):
 
 
 def _make_engine(cfg, params, *, speculate, depth, decode_wrap=None,
-                 batched=True, n_slots=2, netem=None):
+                 batched=True, n_slots=2, netem=None, tracer=None):
     rules = rules_for("serve", make_host_mesh(model=1).axis_names)
     prefill = jax.jit(ST.make_prefill_step(cfg, rules, CACHE_LEN))
     batched_prefill = jax.jit(
@@ -39,7 +39,7 @@ def _make_engine(cfg, params, *, speculate, depth, decode_wrap=None,
                                                       CACHE_LEN),
                   cache_batch_axes=cache_batch_axes_for(cfg), netem=netem,
                   speculate=speculate, pipeline_depth=depth,
-                  batched_prefill_fn=batched_prefill)
+                  batched_prefill_fn=batched_prefill, tracer=tracer)
 
 
 def _submit_workload(eng, cfg, n=5, max_new=14, seed=7):
@@ -164,6 +164,74 @@ def test_batched_prefill_matches_per_request():
             # 3 slots admitted as a group -> fewer dispatches than requests
             assert eng.stats["prefill_dispatches"] < 6
     assert outs[False] == outs[True]
+
+
+@pytest.mark.parametrize("max_new", [14, 3], ids=["long", "short"])
+def test_request_stamps_are_ordered(max_new):
+    """Every request carries submit <= admit <= prefilled <= first commit;
+    it retires after its prefill, and after its first commit unless it
+    ended inside the drain that made that commit (``Request``)."""
+    cfg = _cfg()
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    eng = _make_engine(cfg, params, speculate=True, depth=2)
+    _submit_workload(eng, cfg, n=5, max_new=max_new)
+    eng.run()
+    for r in eng.requests.values():
+        assert r.done and not r.failed
+        assert 0 < r.submit_t <= r.admit_t <= r.prefilled_t <= r.first_t
+        assert r.prefilled_t <= r.finish_t
+        if max_new > 2 * BLOCK_K + 1:      # outlives its first drain
+            assert r.first_t <= r.finish_t
+
+
+@pytest.mark.parametrize("batched", [True, False],
+                         ids=["batched", "per_request"])
+def test_admission_spans_and_counters(batched):
+    """``admission_drains`` counts the drains admission forced, which are
+    the ``frontier.drain`` spans inside ``executor.admit``; each prefill
+    dispatch is a ``prefill.dispatch`` span inside an admission, and
+    ``host_syncs`` counts only the frontier's drains and sync blocks.
+    Spans nest as documented."""
+    from repro.obs import Tracer
+    cfg = _cfg()
+    params = M.init_params(cfg, jax.random.PRNGKey(2))
+    tr = Tracer(annotate=True)
+    eng = _make_engine(cfg, params, speculate=True, depth=4, n_slots=3,
+                       batched=batched, tracer=tr)
+    rng = np.random.default_rng(5)
+    prompts = [list(rng.integers(3, cfg.vocab_size, 7)) for _ in range(6)]
+    for p in prompts[:2]:
+        eng.submit(p, 24)
+    for _ in range(6):              # blocks in flight, then admissions
+        eng.step_block()
+    for p in prompts[2:]:
+        eng.submit(p, 24)
+    eng.run()
+    st = eng.stats
+    spans = tr.spans("serve.stream0")
+    by = lambda name: [s for s in spans if s["name"] == name]
+    within = lambda c, p: (p["ts"] <= c["ts"]
+                           and c["ts"] + c["dur"] <= p["ts"] + p["dur"])
+    inside = lambda name, parents: [c for c in by(name)
+                                    if any(within(c, p) for p in parents)]
+    admits = by("executor.admit")
+    assert sum(a["args"]["n"] for a in admits) == st["admitted"] == 6
+    assert st["admission_drains"] >= 1
+    assert len(inside("frontier.drain", admits)) == st["admission_drains"]
+    assert st["prefill_dispatches"] == len(by("prefill.dispatch"))
+    assert len(inside("prefill.dispatch", admits)) \
+        == st["prefill_dispatches"]
+    assert len(inside("executor.scatter", by("prefill.dispatch"))) \
+        == len(by("executor.scatter")) == st["prefill_dispatches"]
+    drains = by("frontier.drain")
+    assert len(drains) == st["host_syncs"] - st["sync_blocks"]
+    waits = inside("frontier.wait", drains)
+    assert len(waits) == len(by("frontier.wait")) \
+        == len(inside("frontier.apply", drains)) \
+        == st["validated_blocks"] + st["mispredicts"]
+    assert len(inside("frontier.commit", drains)) == len(drains)
+    names = {e["name"] for e in tr.events}
+    assert not names & {"host_sync", "request.done"}
 
 
 def test_replayer_validates_args_and_dispatches_on_avals():
