@@ -240,27 +240,45 @@ def kv_quantize(t):
     return jnp.clip(jnp.round(f / s), -127, 127).astype(jnp.int8), s
 
 
-def gqa_decode(p, x, cfg, cache, pos):
-    """x [B,1,D]; cache dict {'k','v'[, 'k_s','v_s']} -> (out, new cache)."""
+def layer_slice(t, layer):
+    """One layer of a stage's stacked cache leaf; ``t`` itself when
+    ``layer`` is None (an unstacked, one-block stage)."""
+    if layer is None:
+        return t
+    return jax.lax.dynamic_index_in_dim(t, layer, keepdims=False)
+
+
+def _write_rows(cache, rows, layer, slot):
+    """Set row ``[layer, b, slot[b]]`` (``[b, slot[b]]`` when ``layer`` is
+    None) of each cache leaf named in ``rows`` [B, ...]."""
+    bidx = jnp.arange(slot.shape[0])
+    at = (bidx, slot) if layer is None else (layer, bidx, slot)
+    return {n: cache[n].at[at].set(r) for n, r in rows.items()}
+
+
+def gqa_decode(p, x, cfg, cache, pos, layer=None):
+    """x [B,1,D]; cache dict {'k','v'[, 'k_s','v_s']} -> (out, new cache).
+
+    With ``layer`` the leaves are the stage's whole stack [n,B,W,...],
+    carried through the decode layer scan: this layer's new K/V row is
+    written in place at ``[layer, b, slot_b]`` (the ring slot ``pos % W``
+    under a sliding window) and attention reads ``cache[layer]``. Nothing
+    else of the cache is copied. Without ``layer`` the leaves are one
+    layer's [B,W,...].
+    """
     q, k, v = gqa_qkv(p, x, cfg, pos[:, None])
-    W = cache["k"].shape[1]
+    W = cache["k"].shape[-3]
     slot = (pos % W) if cfg.sliding_window else pos
-    bidx = jnp.arange(x.shape[0])
     if cfg.kv_quant:
         kq, ks = kv_quantize(k[:, 0])
         vq, vs = kv_quantize(v[:, 0])
-        cache = {"k": cache["k"].at[bidx, slot].set(kq),
-                 "k_s": cache["k_s"].at[bidx, slot].set(ks),
-                 "v": cache["v"].at[bidx, slot].set(vq),
-                 "v_s": cache["v_s"].at[bidx, slot].set(vs)}
-        o = decode_attention(q, cache["k"], cache["v"], pos,
-                             window=cfg.sliding_window,
-                             k_scale=cache["k_s"], v_scale=cache["v_s"])
+        rows = {"k": kq, "k_s": ks, "v": vq, "v_s": vs}
     else:
-        cache = {"k": cache["k"].at[bidx, slot].set(k[:, 0]),
-                 "v": cache["v"].at[bidx, slot].set(v[:, 0])}
-        o = decode_attention(q, cache["k"], cache["v"], pos,
-                             window=cfg.sliding_window)
+        rows = {"k": k[:, 0], "v": v[:, 0]}
+    cache = _write_rows(cache, rows, layer, slot)
+    c = {n: layer_slice(t, layer) for n, t in cache.items()}
+    o = decode_attention(q, c["k"], c["v"], pos, window=cfg.sliding_window,
+                         k_scale=c.get("k_s"), v_scale=c.get("v_s"))
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), cache
 
 
@@ -312,17 +330,22 @@ def mla_attention(p, x, cfg, *, rules=None):
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), (c_kv, k_rope)
 
 
-def mla_decode(p, x, cfg, cache_c, cache_kr, pos):
-    """Absorbed-matrices decode: scores/combine in the 512-d latent space."""
+def mla_decode(p, x, cfg, cache, pos, layer=None):
+    """Absorbed-matrices decode: scores/combine in the 512-d latent space.
+
+    cache {'c','kr'} -> (out, new cache), stacked or not as in
+    ``gqa_decode``: with ``layer`` one latent row is written in place into
+    the carried stack and attention reads ``cache[layer]``.
+    """
     m = cfg.mla
-    B = x.shape[0]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
     q_rope = rope(q_rope, pos[:, None], cfg.rope_theta)
     c_kv, k_rope = _mla_latent(p, x, cfg, pos[:, None])
-    bidx = jnp.arange(B)
-    cache_c = cache_c.at[bidx, pos].set(c_kv[:, 0])
-    cache_kr = cache_kr.at[bidx, pos].set(k_rope[:, 0])
+    cache = _write_rows(cache, {"c": c_kv[:, 0], "kr": k_rope[:, 0]},
+                        layer, pos)
+    cache_c = layer_slice(cache["c"], layer)
+    cache_kr = layer_slice(cache["kr"], layer)
     # absorb W_uk into q:   q_lat [B,H,R]
     q_lat = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], p["w_uk"])
     s = jnp.einsum("bhr,bsr->bhs", q_lat, cache_c,
@@ -336,7 +359,7 @@ def mla_decode(p, x, cfg, cache_c, cache_kr, pos):
                      preferred_element_type=jnp.float32).astype(x.dtype)
     o = jnp.einsum("bhr,rhk->bhk", ctx, p["w_uv"])
     out = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None]
-    return out, cache_c, cache_kr
+    return out, cache
 
 
 # ------------------------------------------------------------------ MLP ----

@@ -387,12 +387,18 @@ def cache_axes(cfg: ModelConfig):
     return axes
 
 
-def _block_decode(kind, p, h, cache, pos, cfg, shared=None, rules=None):
-    """Single-token decode for one block. h [B,1,D]."""
+def _block_decode(kind, p, h, cache, pos, cfg, shared=None, rules=None,
+                  layer=None):
+    """Single-token decode for one block. h [B,1,D].
+
+    ``cache`` is the block's cache with its K/V leaves (``_carried``) as
+    the stage's whole stack when ``layer`` is given, and its recurrent
+    state as this block's slice; returns (h, cache) of the same layout.
+    """
     p = _maybe_dequant(p)
     if kind in ("dense", "moe", "enc"):
         hn = L.apply_norm(p["ln1"], h, cfg.norm)
-        a, cache = L.gqa_decode(p["attn"], hn, cfg, cache, pos)
+        a, cache = L.gqa_decode(p["attn"], hn, cfg, cache, pos, layer)
         if cfg.parallel_block:
             h = h + a + L.apply_mlp(p["mlp"], hn, cfg, rules)
         else:
@@ -405,8 +411,7 @@ def _block_decode(kind, p, h, cache, pos, cfg, shared=None, rules=None):
             h = h + m
     elif kind in ("mla_dense", "mla_moe"):
         hn = L.apply_norm(p["ln1"], h, cfg.norm)
-        a, cc, ckr = L.mla_decode(p["attn"], hn, cfg, cache["c"], cache["kr"], pos)
-        cache = {"c": cc, "kr": ckr}
+        a, cache = L.mla_decode(p["attn"], hn, cfg, cache, pos, layer)
         h = h + a
         hn2 = L.apply_norm(p["ln2"], h, cfg.norm)
         if kind == "mla_moe":
@@ -417,12 +422,13 @@ def _block_decode(kind, p, h, cache, pos, cfg, shared=None, rules=None):
     elif kind == "dec":
         hn = L.apply_norm(p["ln1"], h, cfg.norm)
         kv_in = {k: cache[k] for k in cache if not k.startswith("x")}
-        a, kv_out = L.gqa_decode(p["attn"], hn, cfg, kv_in, pos)
+        a, kv_out = L.gqa_decode(p["attn"], hn, cfg, kv_in, pos, layer)
         h = h + a
         hx = L.apply_norm(p["lnx"], h, cfg.norm)
         q = jnp.einsum("bsd,dhk->bshk", hx, p["xattn"]["wq"])
-        o = L.decode_attention(q, cache["xk"], cache["xv"],
-                               jnp.full_like(pos, cache["xk"].shape[1] - 1))
+        xk = L.layer_slice(cache["xk"], layer)
+        xv = L.layer_slice(cache["xv"], layer)
+        o = L.decode_attention(q, xk, xv, jnp.full_like(pos, xk.shape[1] - 1))
         h = h + jnp.einsum("bshk,hkd->bsd", o, p["xattn"]["wo"])
         hn2 = L.apply_norm(p["ln2"], h, cfg.norm)
         h = h + L.apply_mlp(p["mlp"], hn2, cfg, rules)
@@ -442,7 +448,7 @@ def _block_decode(kind, p, h, cache, pos, cfg, shared=None, rules=None):
             new_m.append(ci)
         hn = L.apply_norm(shared["ln1"], h, cfg.norm)
         a, attn_cache = L.gqa_decode(shared["attn"], hn, cfg,
-                                     cache["attn"], pos)
+                                     cache["attn"], pos, layer)
         h = h + a
         hn = L.apply_norm(shared["ln2"], h, cfg.norm)
         h = h + L.apply_mlp(shared["mlp"], hn, cfg, rules)
@@ -470,8 +476,29 @@ def _block_decode(kind, p, h, cache, pos, cfg, shared=None, rules=None):
     return h, cache
 
 
+def _carried(kind, cache):
+    """Keys of a stage's cache that the decode layer scan carries whole:
+    those indexed by position, where a step writes one row per layer.
+    Recurrent state (mamba, xLSTM, zamba's mamba part) is rewritten whole
+    each step, so it stays per-layer ``xs``/``ys``."""
+    if kind in ("mamba", "xlstm_group"):
+        return ()
+    if kind == "zamba_group":
+        return ("attn",)
+    return tuple(cache)
+
+
 def decode_step(params, cfg: ModelConfig, tokens, pos, caches, rules=None):
-    """tokens [B], pos [B] -> (logits [B,V], new caches)."""
+    """tokens [B], pos [B] -> (logits [B,V], new caches).
+
+    The cache layout is ``init_cache``'s. Per stage the layer scan carries
+    the stacked K/V leaves (``_carried``: dense, moe, enc, dec, mla_* and
+    zamba's shared attention) whole; each layer writes its new row at
+    ``[layer, b, slot_b]`` in place and attends over ``cache[layer]``, so
+    a step writes one row per layer and no layer slice is re-stacked.
+    Params and the layer index are the scan's ``xs``; recurrent state goes
+    in as ``xs`` and comes out as ``ys``.
+    """
     params = {k: (_maybe_dequant(v) if k != "stages" else v)
               for k, v in params.items()}
     h = jnp.take(params["embed"], tokens[:, None], axis=0)
@@ -483,17 +510,23 @@ def decode_step(params, cfg: ModelConfig, tokens, pos, caches, rules=None):
         if cfg.family == "audio" and st.kind == "enc":
             new_caches.append(cache)  # encoder is inactive during decode
             continue
+        keys = _carried(st.kind, cache)
+        kv = {k: cache[k] for k in keys}
+        state = {k: t for k, t in cache.items() if k not in keys}
 
-        def body(hh, xs, _kind=st.kind):
-            pl, cl = xs
-            hh, cl = _block_decode(_kind, pl, hh, cl, pos, cfg,
-                                   shared=params.get("shared"), rules=rules)
-            return hh, cl
+        def body(carry, xs, _kind=st.kind):
+            hh, kv = carry
+            pl, layer, state = xs
+            hh, c = _block_decode(_kind, pl, hh, {**kv, **state}, pos, cfg,
+                                  shared=params.get("shared"), rules=rules,
+                                  layer=layer)
+            return (hh, {k: c[k] for k in kv}), {k: c[k] for k in state}
         if st.n == 1:
-            h, nc = body(h, (sp, cache))
+            (h, kv), state = body((h, kv), (sp, None, state))
         else:
-            h, nc = jax.lax.scan(body, h, (sp, cache))
-        new_caches.append(nc)
+            (h, kv), state = jax.lax.scan(
+                body, (h, kv), (sp, jnp.arange(st.n), state))
+        new_caches.append({**kv, **state})
     h = L.apply_norm(params["final_norm"], h, cfg.norm)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = (h[:, 0] @ head) * cfg.logit_scale

@@ -76,6 +76,80 @@ def test_prefill_decode_matches_forward(arch):
     assert rel < 0.05, f"{arch}: prefill+decode diverges from forward ({rel})"
 
 
+def _slot_caches(cfg, per_slot):
+    """Concatenate one-slot caches along each leaf's batch axis."""
+    return jax.tree.map(
+        lambda ax, *ts: jnp.concatenate(ts, axis=ax.index("batch")),
+        M.cache_axes(cfg), *per_slot, is_leaf=lambda x: isinstance(x, tuple))
+
+
+@pytest.mark.parametrize("arch,kv_quant", [
+    ("qwen2.5-3b", False), ("starcoder2-7b", False), ("mixtral-8x22b", False),
+    ("qwen2-72b", True), ("deepseek-v2-lite-16b", False),
+    ("whisper-large-v3", False)])
+def test_fused_decode_writes_only_its_rows(arch, kv_quant):
+    """One fused block of K steps over slots at mixed positions (under SWA
+    one slot crosses the window inside the block, one has wrapped): every
+    cache row but the written [layer, slot, pos..pos+K-1] rows is
+    bit-identical to its input, and the written rows match what prefill of
+    the extended sequence puts there."""
+    cfg = smoke_shrink(get_config(arch))
+    if cfg.moe is not None:  # disable capacity drops for exactness
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=100.0))
+    cfg = dataclasses.replace(cfg, kv_quant=kv_quant)
+    params = M.init_params(cfg, KEY)
+    K, cache_len, lens = 4, 64, (13, 30, 45)
+    toks = jax.random.randint(jax.random.PRNGKey(7),
+                              (len(lens), max(lens) + 1), 0, cfg.vocab_size)
+    jit_prefill = jax.jit(M.prefill, static_argnums=(1, 3))
+
+    def prefill(b, seq):
+        batch = {"tokens": seq[None]}
+        if cfg.family == "audio":
+            batch["frames"] = jax.random.normal(
+                jax.random.PRNGKey(10 + b),
+                (1, cfg.encdec.encoder_seq, cfg.d_model)).astype(jnp.bfloat16)
+        return jit_prefill(params, cfg, batch, cache_len)[1]
+
+    caches = _slot_caches(cfg, [prefill(b, toks[b, :n])
+                                for b, n in enumerate(lens)])
+    first = jnp.array([toks[b, n] for b, n in enumerate(lens)])
+    pos = jnp.array(lens, jnp.int32)
+    fused = jax.jit(ST.make_fused_decode_step(cfg, None, k=K, eos_id=-1))
+    out, new = fused(params, first, pos, caches)
+    fed = jnp.concatenate([first[:, None], out["tokens"][:, :K - 1]], 1)
+    refs = [prefill(b, jnp.concatenate([toks[b, :n], fed[b]]))
+            for b, n in enumerate(lens)]
+
+    kinds = [st.kind for st in M.build_stages(cfg)]
+    flat = jax.tree_util.tree_flatten_with_path(
+        M.cache_axes(cfg), is_leaf=lambda x: isinstance(x, tuple))[0]
+    for (path, ax), old, got, *ref in zip(
+            flat, *(jax.tree.leaves(t) for t in (caches, new, *refs))):
+        old, got = np.asarray(old), np.asarray(got)
+        name = jax.tree_util.keystr(path)
+        if kinds[path[0].idx] == "enc" or "'x" in name:
+            assert old.tobytes() == got.tobytes(), name  # read-only
+            continue
+        b_ax, s_ax = ax.index("batch"), ax.index("kv_seq")
+        W = old.shape[s_ax]
+        written = np.zeros(old.shape, bool)
+        for b, n in enumerate(lens):
+            slots = [p % W if cfg.sliding_window else p
+                     for p in range(n, n + K)]
+            at = [slice(None)] * old.ndim
+            at[b_ax], at[s_ax] = b, slots
+            written[tuple(at)] = True
+            want = np.take(np.asarray(ref[b]), 0, axis=b_ax).astype(np.float32)
+            have = np.take(got, b, axis=b_ax).astype(np.float32)
+            want, have = (np.take(t, slots, axis=s_ax - 1)
+                          for t in (want, have))
+            rel = np.abs(have - want).max() / (np.abs(want).max() + 1e-9)
+            assert rel < 0.05, (name, b, rel)
+        assert old[~written].tobytes() == got[~written].tobytes(), name
+
+
 def test_param_counts_match_analytic():
     """Analytic param_count ~ actual materialized count (within 5%)."""
     for arch in ("qwen2.5-3b", "mixtral-8x22b", "xlstm-350m"):

@@ -12,6 +12,7 @@ import functools
 import importlib.util
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -113,23 +114,54 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     _compile_kernel(kernel, one_chip, *shapes)
 
 
-def test_qwen_decode_step_compiles_and_fits_one_chip(topo):
-    """qwen2.5-3b's fused decode step at published widths (depth cut to 2
-    layers) compiles for one v5e with the placement the Workload records
-    it under, and its memory fits the chip's HBM."""
-    cfg = dataclasses.replace(get_config("qwen2.5-3b"), num_layers=2)
+def _compile_qwen_decode(topo, layers, batch, cache_len=1024):
+    """qwen2.5-3b's fused decode step at published widths, depth cut to
+    ``layers``, compiled for one v5e with the placement the Workload
+    records it under. Returns (compiled, bytes of one stacked K/V cache
+    leaf, that leaf's HLO shape)."""
+    cfg = dataclasses.replace(get_config("qwen2.5-3b"), num_layers=layers)
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
-    wl = Workspace().workload(cfg, mesh=mesh, cache_len=1024, block_k=8,
-                              batch=4)
+    wl = Workspace().workload(cfg, mesh=mesh, cache_len=cache_len,
+                              block_k=8, batch=batch)
     fn, specs, donate = wl.step("decode")
     with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=wl.in_shardings("decode"),
                            donate_argnums=donate).lower(*specs).compile()
+    shape = (layers, batch, cache_len, cfg.num_kv_heads, cfg.hd())
+    leaf = f"bf16[{','.join(map(str, shape))}]"
+    return compiled, int(np.prod(shape)) * 2, leaf
+
+
+def _whole_leaf_copies(compiled, leaf):
+    """HLO copies (sync or async) whose result is a whole stacked leaf."""
+    return [ln for ln in compiled.as_text().splitlines()
+            if re.search(r"\bcopy(-start)?\(", ln)
+            and leaf in re.split(r"\bcopy(-start)?\(", ln)[0]]
+
+
+def test_qwen_decode_step_compiles_and_fits_one_chip(topo):
+    """qwen2.5-3b's fused decode step at published widths (depth cut to 2
+    layers) compiles for one v5e with the placement the Workload records
+    it under, its memory fits the chip's HBM, and it writes the donated
+    caches in place: no whole stacked cache leaf is copied, and its
+    temporaries are smaller than one leaf."""
+    compiled, leaf_bytes, leaf = _compile_qwen_decode(topo, 2, 4)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.alias_size_in_bytes > 0          # the caches are donated
     assert 0 < total < V5E_HBM_BYTES
+    assert mem.temp_size_in_bytes < leaf_bytes
+    assert _whole_leaf_copies(compiled, leaf) == []
+
+
+def test_qwen_decode_step_carries_the_cache_at_32_slots(topo):
+    """At 4 layers x 32 slots x 1024 the copies the layer scan would make of
+    a re-stacked cache no longer fit in on-chip memory and would show as
+    temporaries of 1.5 leaves; the carried cache needs well under one."""
+    compiled, leaf_bytes, leaf = _compile_qwen_decode(topo, 4, 32)
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes / 4
+    assert _whole_leaf_copies(compiled, leaf) == []
 
 
 def test_chip_smoke_serving_body_replay_equals_live(chip_smoke):
